@@ -38,21 +38,26 @@ def time_call(fn, *args, repeat: int = 3, **kw) -> float:
     return float(np.median(ts))
 
 
+def join_columns(rows: int, seed: int = 0) -> tuple[dict, dict]:
+    """The paper's microbenchmark data as NumPy columns: two tables of
+    `rows` rows, unique integer keys drawn from [0, 2 * rows)."""
+    rng = np.random.default_rng(seed)
+    left = {"k": rng.permutation(rows * 2)[:rows].astype(np.int32),
+            "v": rng.integers(0, 1 << 20, rows).astype(np.int32)}
+    right = {"k": rng.permutation(rows * 2)[:rows].astype(np.int32),
+             "w": rng.integers(0, 1 << 20, rows).astype(np.int32)}
+    return left, right
+
+
+def join_capacity(rows: int, cap_slack: float = 1.1) -> int:
+    return int(rows * cap_slack) + 8
+
+
 def gen_join_tables(rows: int, seed: int = 0, cap_slack: float = 1.1):
     """The paper's microbenchmark data: two tables, ~unique integer keys."""
-    rng = np.random.default_rng(seed)
-    cap = int(rows * cap_slack) + 8
-    left = Table.from_dict(
-        {"k": rng.permutation(rows * 2)[:rows].astype(np.int32),
-         "v": rng.integers(0, 1 << 20, rows).astype(np.int32)},
-        capacity=cap,
-    )
-    right = Table.from_dict(
-        {"k": rng.permutation(rows * 2)[:rows].astype(np.int32),
-         "w": rng.integers(0, 1 << 20, rows).astype(np.int32)},
-        capacity=cap,
-    )
-    return left, right
+    left, right = join_columns(rows, seed)
+    cap = join_capacity(rows, cap_slack)
+    return Table.from_dict(left, capacity=cap), Table.from_dict(right, capacity=cap)
 
 
 def measure_local_join_seconds(rows: int) -> float:

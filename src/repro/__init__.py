@@ -16,10 +16,6 @@ Public API re-exports the stable surface; submodules hold the substrate:
 
 __version__ = "1.0.0"
 
-from repro import compat as _compat
-
-_compat.install()
-
 from repro.core.communicator import (  # noqa: F401
     Communicator,
     CommEvent,

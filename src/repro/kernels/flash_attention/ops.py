@@ -12,10 +12,6 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention import kernel, ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def flash_attention(
     q: jax.Array,          # [B, Tq, H, hd]
     k: jax.Array,          # [B, Tk, KV, hd]
@@ -28,7 +24,6 @@ def flash_attention(
     q_block: int = 512,
     kv_block: int = 512,
     force_kernel: bool = False,
-    interpret: bool | None = None,
 ) -> jax.Array:
     b, tq, h, hd = q.shape
     _, tk, kvh, _ = k.shape
@@ -39,13 +34,12 @@ def flash_attention(
     kh = k.transpose(0, 2, 1, 3).reshape(b * kvh, tk, hd)
     vh = v.transpose(0, 2, 1, 3).reshape(b * kvh, tk, hd)
 
-    use_kernel = force_kernel or _on_tpu()
-    if use_kernel:
+    on_tpu = jax.default_backend() == "tpu"
+    if force_kernel or on_tpu:
         out = kernel.flash_attention(
             qh, kh, vh, kvl,
             groups=g, causal=causal, window=window, softcap=softcap,
-            q_block=q_block, kv_block=kv_block,
-            interpret=(not _on_tpu()) if interpret is None else interpret,
+            q_block=q_block, kv_block=kv_block, interpret=not on_tpu,
         )
     else:
         out = ref.attention_ref(

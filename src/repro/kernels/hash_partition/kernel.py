@@ -2,9 +2,10 @@
 
 Elementwise murmur-style finalizer over integer keys; one VMEM block of keys
 per grid step, fused hash -> bucket modulo so the partition phase reads keys
-from HBM exactly once.  Block = 8 x 1024 int32 (32 KiB) keeps the VPU lanes
-full; the op is memory-bound so the kernel's job is simply to not waste the
-single pass.
+from HBM exactly once.  Keys are laid out lane-dense as ``[rows, 1024]`` and
+each grid step takes ``block // 1024`` rows (8 x 1024 int32 = 32 KiB at the
+default), so every block is a whole number of (8, 128) tiles.  The op is
+memory-bound; the kernel's job is simply to not waste the single pass.
 """
 
 from __future__ import annotations
@@ -15,9 +16,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import lane_dense
+
 _M1 = 0x85EBCA6B
 _M2 = 0xC2B2AE35
 _SEED_MIX = 0x9E3779B9
+_LANES = 1024
 
 
 def _hash_kernel(x_ref, h_ref, b_ref, *, seed: int, num_partitions: int):
@@ -41,24 +45,21 @@ def hash_partition(
     block: int = 8192,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    n = keys.shape[0]
-    block = min(block, n)
-    pad = (-n) % block
-    x = jnp.pad(keys, (0, pad)).reshape(-1, block)
+    """``block`` keys per grid step, rounded up to whole (8, 1024) tiles."""
+    x, block_rows = lane_dense(keys, block, _LANES)
     rows = x.shape[0]
+    spec = pl.BlockSpec((block_rows, _LANES), lambda i: (i, 0))
     kernel = functools.partial(_hash_kernel, seed=seed, num_partitions=num_partitions)
     h, b = pl.pallas_call(
         kernel,
-        grid=(rows,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-        ],
+        grid=(rows // block_rows,),
+        in_specs=[spec],
+        out_specs=[spec, spec],
         out_shape=[
-            jax.ShapeDtypeStruct((rows, block), jnp.uint32),
-            jax.ShapeDtypeStruct((rows, block), jnp.int32),
+            jax.ShapeDtypeStruct((rows, _LANES), jnp.uint32),
+            jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),
         ],
         interpret=interpret,
     )(x)
+    n = keys.shape[0]
     return h.reshape(-1)[:n], b.reshape(-1)[:n]

@@ -8,9 +8,9 @@ from repro.kernels.hash_partition import kernel, ref
 
 
 def hash_partition(keys, *, num_partitions: int, seed: int = 0, force_kernel: bool = False):
-    if force_kernel or jax.default_backend() == "tpu":
+    on_tpu = jax.default_backend() == "tpu"
+    if force_kernel or on_tpu:
         return kernel.hash_partition(
-            keys, num_partitions=num_partitions, seed=seed,
-            interpret=jax.default_backend() != "tpu",
+            keys, num_partitions=num_partitions, seed=seed, interpret=not on_tpu,
         )
     return ref.hash_partition_ref(keys, num_partitions=num_partitions, seed=seed)

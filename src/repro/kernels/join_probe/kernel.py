@@ -1,14 +1,14 @@
 """Sorted-probe join Pallas kernel.
 
-Branchless binary search of each left key against a sorted right-key page
-held in VMEM.  Grid walks left-key blocks; the right page (<= `page` keys,
-128-aligned) is resident across the whole grid (constant index map), so HBM
-reads the probe side exactly once.  log2(page) fori iterations of pure
-VPU selects — no data-dependent control flow.
-
-ops.py handles multi-page probe sides by first-level searchsorted over page
-boundaries and one kernel call per page bucket (falls back to the oracle on
-CPU or when the probe side exceeds VMEM budget).
+For each left key, the lower-bound position in a sorted right-key page and
+whether the key is present.  The right page (<= ``ops._MAX_PAGE`` keys) sits
+in SMEM for the whole grid (HBM reads the probe side exactly once); the grid
+walks lane-dense ``[block // 128, 128]`` tiles of left keys.  Each step
+streams the page as scalars and counts, per lane, the right keys below the
+left key (the lower bound) and those equal to it (the hit): pure VPU
+compares and adds, no gathers and no data-dependent control flow.  Mosaic
+lowers no vector gather across a page, so a per-lane binary search is not
+expressible; the scan costs O(page) per key instead of O(log page).
 """
 
 from __future__ import annotations
@@ -18,27 +18,34 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import lane_dense
+
+_LANES = 128
+_UNROLL = 8
 
 
-def _probe_kernel(rk_ref, lk_ref, idx_ref, hit_ref, *, page: int, steps: int):
-    rkeys = rk_ref[0]                      # [page] int32 sorted (padded with INT32_MAX)
-    lkeys = lk_ref[0]                      # [bn]
+def _probe_kernel(rk_ref, lk_ref, idx_ref, hit_ref, *, page: int):
+    lkeys = lk_ref[...]                    # [block_rows, 128] int32
 
-    lo = jnp.zeros_like(lkeys)
-    hi = jnp.full_like(lkeys, page)
-    def body(_, carry):
-        lo, hi = carry
-        mid = (lo + hi) // 2
-        mv = rkeys[jnp.clip(mid, 0, page - 1)]
-        go_right = mv < lkeys
-        lo = jnp.where(go_right, mid + 1, lo)
-        hi = jnp.where(go_right, hi, mid)
-        return lo, hi
-    lo, hi = jax.lax.fori_loop(0, steps, body, (lo, hi))
-    pos = jnp.clip(lo, 0, page - 1)
-    found = rkeys[pos] == lkeys
-    idx_ref[0] = pos.astype(jnp.int32)
-    hit_ref[0] = found
+    def count(j, carry):
+        below, equal = carry
+        r = rk_ref[j]
+        return below + (lkeys > r).astype(jnp.int32), equal + (lkeys == r).astype(jnp.int32)
+
+    def unrolled(g, carry):
+        for u in range(_UNROLL):
+            carry = count(g * _UNROLL + u, carry)
+        return carry
+
+    zeros = jnp.zeros_like(lkeys)
+    carry = jax.lax.fori_loop(0, page // _UNROLL, unrolled, (zeros, zeros))
+    for j in range(page - page % _UNROLL, page):
+        carry = count(j, carry)
+    below, equal = carry
+    idx_ref[...] = jnp.minimum(below, page - 1)
+    hit_ref[...] = (equal > 0).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -46,32 +53,24 @@ def probe_sorted(
     right_keys: jax.Array,   # [page] int32 sorted, padded with INT32_MAX
     left_keys: jax.Array,    # [n] int32
     *,
-    block: int = 2048,
+    block: int = 4096,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
+    """``block`` left keys per grid step, rounded up to whole (8, 128) tiles."""
     page = right_keys.shape[0]
-    steps = max(1, int(page).bit_length())  # lower-bound search: lo==hi needs ceil(log2(page))+1
-    n = left_keys.shape[0]
-    block = min(block, n)
-    pad = (-n) % block
-    lk = jnp.pad(left_keys, (0, pad)).reshape(-1, block)
+    lk, block_rows = lane_dense(left_keys, block, _LANES)
     rows = lk.shape[0]
-    kernel = functools.partial(_probe_kernel, page=page, steps=steps)
+    spec = pl.BlockSpec((block_rows, _LANES), lambda i: (i, 0))
     idx, hit = pl.pallas_call(
-        kernel,
-        grid=(rows,),
-        in_specs=[
-            pl.BlockSpec((1, page), lambda i: (0, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-        ],
+        functools.partial(_probe_kernel, page=page),
+        grid=(rows // block_rows,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec],
+        out_specs=[spec, spec],
         out_shape=[
-            jax.ShapeDtypeStruct((rows, block), jnp.int32),
-            jax.ShapeDtypeStruct((rows, block), jnp.bool_),
+            jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),
+            jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),
         ],
         interpret=interpret,
-    )(right_keys.reshape(1, page), lk)
-    return idx.reshape(-1)[:n], hit.reshape(-1)[:n]
+    )(right_keys, lk)
+    n = left_keys.shape[0]
+    return idx.reshape(-1)[:n], hit.reshape(-1)[:n].astype(jnp.bool_)

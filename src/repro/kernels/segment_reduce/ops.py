@@ -18,13 +18,12 @@ def segment_sum(
     force_kernel: bool = False,
 ) -> jax.Array:
     """Sorted-segment sum; kernel path on TPU (or forced), oracle otherwise."""
-    if not (force_kernel or jax.default_backend() == "tpu"):
+    on_tpu = jax.default_backend() == "tpu"
+    if not (force_kernel or on_tpu):
         return ref.segment_sum_ref(seg_ids, values, num_segments)
     partials, bases = kernel.segment_sum_blocked(
-        seg_ids, values, block=block, max_seg=max_seg,
-        interpret=jax.default_backend() != "tpu",
+        seg_ids, values, block=block, max_seg=max_seg, interpret=not on_tpu,
     )
-    rows = partials.shape[0]
     # combine: partial j of block i belongs to segment bases[i] + j
     seg_flat = (bases[:, None] + jnp.arange(max_seg)[None, :]).reshape(-1)
     seg_flat = jnp.clip(seg_flat, 0, num_segments)  # overflow slot dropped below

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this AOT-compiles the real step function (train_step /
@@ -22,6 +19,7 @@ Usage:
 
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -172,8 +170,6 @@ def run_cell(
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list | tuple):  # older jax wraps the dict in a list
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     stats = hlo_analysis.analyze(hlo, chips)
     mf = hlo_analysis.model_flops(cfg, cell)
@@ -225,6 +221,8 @@ def _save(tag: str, record: dict, save: bool):
 
 
 def main():
+    # the fake pod: must precede the first device query of the process
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

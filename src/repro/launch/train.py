@@ -4,6 +4,8 @@ Library entry used by ``examples/train_pipeline.py`` and runnable directly:
 
     PYTHONPATH=src python -m repro.launch.train --arch minicpm-2b --reduced \
         --steps 200
+    PYTHONPATH=src python -m repro.launch.train --arch minicpm-2b --no-reduced \
+        --num-layers 4 --batch 2 --seq-len 2048 --steps 5      # one chip
 
 On real hardware the same driver runs under the production mesh (pjit with
 the sharding rules); on this host it trains the reduced config on one
@@ -14,6 +16,7 @@ resumes from the latest step (tested in test_integration.py).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 from pathlib import Path
 
@@ -25,6 +28,8 @@ from repro.data import pipeline
 from repro.dist import checkpoint as ckpt
 from repro.dist import compression
 from repro.dist.object_store import Store, as_store
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import api
 from repro.train import optimizer as opt
 from repro.train.train_step import make_train_step
@@ -168,11 +173,13 @@ def train(
     if use_explicit_dp:
         from repro.train.train_step import make_compressed_dp_train_step
 
-        mesh = jax.make_mesh((dp,), ("data",))
+        mesh = make_mesh((dp,), ("data",))
         step_fn, init_err = make_compressed_dp_train_step(cfg, opt_cfg, mesh)
         grad_err = init_err(params)
     else:
         step_fn = jax.jit(make_train_step(cfg, opt_cfg))
+    log(f"train step: {'explicit compressed-dp shard_map' if use_explicit_dp else 'implicit-dp jit'} "
+        f"over {dp} device(s)")
 
     def ckpt_tree():
         tree = {"params": params, "opt": opt_state}
@@ -326,7 +333,11 @@ def train(
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="minicpm-2b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction, default=True,
+                    help="shrink to the tiny CPU smoke widths (--no-reduced keeps "
+                         "the published widths)")
+    ap.add_argument("--num-layers", type=int, default=None,
+                    help="cut the config's depth to this many layers")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq-len", type=int, default=64)
@@ -365,9 +376,12 @@ def main():
                          "JSON (convert with scripts/trace_to_chrome.py for "
                          "chrome://tracing)")
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = configs.get(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if args.num_layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=args.num_layers)
     comm_session = None
     # --trace-out wants comm spans too, so it also builds the modeled session
     if args.resume or (args.burst_at is not None and args.burst_world > 0) \

@@ -1,9 +1,11 @@
 """Shared neural layers: RMSNorm, RoPE, GQA attention (windowed / cached),
-gated MLP, embeddings.  Pure jnp; kernels/ holds the Pallas twins.
+gated MLP, embeddings.  Pure jnp.
 
-All attention here is the XLA path (`impl="xla"`); `repro.kernels.
-flash_attention.ops` provides the Pallas TPU kernel with identical semantics
-(validated against these functions in interpret mode).
+All attention here is XLA: `attention(impl="auto")` picks the direct path
+below `_FLASH_MIN_Q` query positions and the blocked online-softmax path
+above.  The models call no Pallas kernel; `repro.kernels.flash_attention`
+is a separate kernel of the same semantics, tested against
+`attention(impl="direct")`.
 """
 
 from __future__ import annotations

@@ -22,19 +22,27 @@ def temperature_sample(logits: jax.Array, key: jax.Array, temp: float = 0.8) -> 
     return jax.random.categorical(key, logits[:, -1] / temp, axis=-1).astype(jnp.int32)[:, None]
 
 
-def make_prefill_step(cfg: ArchConfig, ctx=None):
+def make_prefill_step(cfg: ArchConfig, ctx=None, *, with_logits: bool = False):
+    """Prompt -> (next tokens, state); ``with_logits`` also returns the
+    last-position logits [B,1,V] between them."""
+
     def prefill_step(params, batch, state):
         logits, state = api.prefill_fn(cfg, params, batch, state, ctx=ctx)
+        if with_logits:
+            return greedy_sample(logits), logits, state
         return greedy_sample(logits), state
 
     return prefill_step
 
 
-def make_serve_step(cfg: ArchConfig, ctx=None):
-    """One decode iteration: tokens [B,1] + state -> (next tokens, state)."""
+def make_serve_step(cfg: ArchConfig, ctx=None, *, with_logits: bool = False):
+    """One decode iteration: tokens [B,1] + state -> (next tokens, state);
+    ``with_logits`` also returns the step's logits [B,1,V] between them."""
 
     def serve_step(params, tokens, state):
         logits, state = api.decode_fn(cfg, params, tokens, state, ctx=ctx)
+        if with_logits:
+            return greedy_sample(logits), logits, state
         return greedy_sample(logits), state
 
     return serve_step

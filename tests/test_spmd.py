@@ -23,6 +23,7 @@ def run_spmd(body: str, timeout=900) -> str:
         import numpy as np
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
+        from repro.launch.mesh import make_mesh
         """
     ) + textwrap.dedent(body)
     env = dict(os.environ)
@@ -41,7 +42,7 @@ class TestDataframeSPMD:
             """
             from repro.dataframe import Table, ops_dist
             P_ = 8
-            mesh = jax.make_mesh((P_,), ("data",))
+            mesh = make_mesh((P_,), ("data",))
             rng = np.random.default_rng(1)
             n_per = 64
             keys = rng.permutation(P_*n_per).astype(np.int32)
@@ -89,7 +90,7 @@ class TestDataframeSPMD:
             """
             from repro.dataframe import Table, ops_dist
             P_ = 8
-            mesh = jax.make_mesh((P_,), ("data",))
+            mesh = make_mesh((P_,), ("data",))
             rng = np.random.default_rng(4)
             n_per = 64; cap = n_per * 2
             keys = rng.permutation(P_*n_per).astype(np.int32)
@@ -134,7 +135,7 @@ class TestCollectiveLowerings:
         run_spmd(
             """
             from repro.core.backends import direct
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = make_mesh((8,), ("data",))
             rng = np.random.default_rng(2)
             for shape in ((64,), (3, 5), (13,)):
                 x_all = jnp.asarray(rng.normal(size=(8,) + shape), jnp.float32)
@@ -161,7 +162,7 @@ class TestCollectiveLowerings:
         run_spmd(
             """
             from repro.core.backends import mediated
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = make_mesh((8,), ("data",))
             rng = np.random.default_rng(3)
             x_all = jnp.asarray(rng.normal(size=(8, 8, 16, 4)), jnp.float32)
 
@@ -209,7 +210,7 @@ class TestCompressedDPStep:
             batch = {"tokens": jnp.asarray(rng.integers(0, 512, (8, 16)), jnp.int32),
                      "mask": jnp.ones((8, 16), jnp.float32)}
 
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = make_mesh((8,), ("data",))
             step_c, init_err = make_compressed_dp_train_step(cfg, opt_cfg, mesh)
             err = init_err(params)
             step_i = jax.jit(make_train_step(cfg, opt_cfg))
@@ -281,7 +282,7 @@ class TestMoESPMD:
             cfg = configs.get('qwen3-moe-235b-a22b').reduced(
                 num_experts=8, experts_per_token=2, moe_d_ff=32, d_model=64,
                 capacity_factor=8.0)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             ctx = DistContext(mesh=mesh, ep_axis="model", dp_axes=("data",), tp_axis="model")
             blk = M.init_moe_block(cfg, jax.random.PRNGKey(0), 1)
             blk = jax.tree.map(lambda x: x[0], blk)
@@ -300,7 +301,7 @@ class TestCompressionSPMD:
         run_spmd(
             """
             from repro.dist.compression import compressed_pmean
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = make_mesh((8,), ("data",))
             rng = np.random.default_rng(0)
             g_all = jnp.asarray(rng.normal(size=(8, 4096)), jnp.float32)
 
@@ -329,7 +330,7 @@ class TestCompressionSPMD:
         run_spmd(
             """
             from repro.dist.compression import compressed_pmean
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = make_mesh((8,), ("data",))
             rng = np.random.default_rng(1)
             target = jnp.asarray(rng.normal(size=(256,)), jnp.float32)
 
@@ -372,7 +373,7 @@ class TestMiniDryrun:
             from repro.launch import shapes
             from repro.launch.dryrun import lower_cell
             from repro.launch import hlo_analysis as H
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = make_mesh((4, 2), ("data", "model"))
             cfg = configs.get('gemma3-4b').reduced(vocab_size=1024, d_model=256,
                 num_heads=4, head_dim=64, num_kv_heads=2)
             cell = dataclasses.replace(shapes.SHAPES['train_4k'], seq_len=128,
