@@ -7,6 +7,6 @@ such tables, one per mesh shard — exactly the paper's "collection of P
 dataframes or partitions of lengths {N_0..N_{P-1}}".
 """
 
-from repro.dataframe.table import Table, Schema  # noqa: F401
+from repro.dataframe.table import STAGES, Schema, Table, stage  # noqa: F401
 from repro.dataframe.partition import hash32, hash_columns, build_partition_payload  # noqa: F401
 from repro.dataframe import io, ops_local, ops_dist, tensor  # noqa: F401

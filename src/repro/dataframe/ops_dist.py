@@ -43,7 +43,7 @@ from repro.core.backends import direct
 from repro.core.communicator import Communicator
 from repro.dataframe import ops_local
 from repro.dataframe.partition import build_partition_payload
-from repro.dataframe.table import Table, from_stacked
+from repro.dataframe.table import Table, from_stacked, stage
 from repro.dist import compression
 
 
@@ -202,18 +202,19 @@ def shuffle_spmd(table: Table, key: str, axis: str, compress: bool = False) -> T
     """
     p = jax.lax.axis_size(axis)
     payload, counts = build_partition_payload(table, p, [key])
-    recv_counts = direct.alltoallv_counts(counts, axis)
-    recv_payload = {}
-    for name, buf in payload.items():
-        if compress and name != key and jnp.issubdtype(buf.dtype, jnp.floating):
-            q, scales = compression.quantize_slots(buf)
-            q_r = direct.alltoall(q, axis, split_dim=0, concat_dim=0)
-            s_r = direct.alltoall(scales, axis, split_dim=0, concat_dim=0)
-            recv_payload[name] = compression.dequantize_slots(
-                q_r, s_r, buf.shape, buf.dtype
-            )
-        else:
-            recv_payload[name] = direct.alltoall(buf, axis, split_dim=0, concat_dim=0)
+    with stage("exchange"):
+        recv_counts = direct.alltoallv_counts(counts, axis)
+        recv_payload = {}
+        for name, buf in payload.items():
+            if compress and name != key and jnp.issubdtype(buf.dtype, jnp.floating):
+                q, scales = compression.quantize_slots(buf)
+                q_r = direct.alltoall(q, axis, split_dim=0, concat_dim=0)
+                s_r = direct.alltoall(scales, axis, split_dim=0, concat_dim=0)
+                recv_payload[name] = compression.dequantize_slots(
+                    q_r, s_r, buf.shape, buf.dtype
+                )
+            else:
+                recv_payload[name] = direct.alltoall(buf, axis, split_dim=0, concat_dim=0)
     return from_stacked(recv_payload, recv_counts)
 
 

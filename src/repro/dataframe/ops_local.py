@@ -17,12 +17,7 @@ from collections.abc import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.dataframe.table import Table
-
-_BIG = {
-    jnp.int32: jnp.iinfo(jnp.int32).max,
-    jnp.int64: jnp.iinfo(jnp.int64).max,
-}
+from repro.dataframe.table import Table, stage
 
 
 def _key_sentinel(dtype) -> int:
@@ -31,11 +26,12 @@ def _key_sentinel(dtype) -> int:
 
 def sort_by_key(table: Table, key: str) -> Table:
     """Sort valid rows ascending by integer key; padding stays at the back."""
-    keys = table.columns[key]
-    sent = _key_sentinel(keys.dtype)
-    masked = jnp.where(table.valid_mask(), keys, sent)
-    order = jnp.argsort(masked, stable=True)
-    return table.gather(order, table.count)
+    with stage("key_sort"):
+        keys = table.columns[key]
+        sent = _key_sentinel(keys.dtype)
+        masked = jnp.where(table.valid_mask(), keys, sent)
+        order = jnp.argsort(masked, stable=True)
+        return table.gather(order, table.count)
 
 
 # ---------------------------------------------------------------------------
@@ -58,44 +54,45 @@ def groupby_agg(table: Table, key: str, aggs: dict[str, str]) -> Table:
     caller as sum+count (associativity needed for the distributed combiner).
     """
     t = sort_by_key(table, key)
-    keys = t.columns[key]
-    valid = t.valid_mask()
-    sent = _key_sentinel(keys.dtype)
-    keys_m = jnp.where(valid, keys, sent)
-    cap = table.capacity
+    with stage("segment_reduce"):
+        keys = t.columns[key]
+        valid = t.valid_mask()
+        sent = _key_sentinel(keys.dtype)
+        keys_m = jnp.where(valid, keys, sent)
+        cap = table.capacity
 
-    # Segment ids: 0-based rank of each distinct key in sorted order; invalid
-    # rows are parked in an overflow segment `cap` that is sliced away.
-    new_seg = jnp.concatenate(
-        [jnp.ones((1,), jnp.int32), (keys_m[1:] != keys_m[:-1]).astype(jnp.int32)]
-    )
-    new_seg = jnp.where(valid, new_seg, 0)
-    seg = jnp.where(valid, jnp.cumsum(new_seg) - 1, cap)
-    n_groups = jnp.sum(new_seg).astype(jnp.int32)
-
-    out_cols: dict[str, jax.Array] = {}
-    # representative key per group (all rows in a segment share the key)
-    kmin = jnp.iinfo(keys.dtype).min
-    out_cols[key] = jax.ops.segment_max(
-        jnp.where(valid, keys, kmin), seg, cap + 1
-    )[:cap].astype(keys.dtype)
-    for col, op in aggs.items():
-        vals = t.columns[col]
-        if op not in AGGS:
-            raise ValueError(f"unsupported agg {op!r}; have {sorted(AGGS)}")
-        res = AGGS[op](vals, seg, cap + 1)[:cap]
-        out_cols[f"{col}_{op}"] = res.astype(
-            jnp.int32 if op == "count" else table.columns[col].dtype
+        # Segment ids: 0-based rank of each distinct key in sorted order; invalid
+        # rows are parked in an overflow segment `cap` that is sliced away.
+        new_seg = jnp.concatenate(
+            [jnp.ones((1,), jnp.int32), (keys_m[1:] != keys_m[:-1]).astype(jnp.int32)]
         )
+        new_seg = jnp.where(valid, new_seg, 0)
+        seg = jnp.where(valid, jnp.cumsum(new_seg) - 1, cap)
+        n_groups = jnp.sum(new_seg).astype(jnp.int32)
 
-    out = Table(out_cols, n_groups)
-    # zero padding rows for determinism (segment_max yields dtype-min there)
-    mask = out.valid_mask()
-    cols = {
-        k: jnp.where(mask.reshape((-1,) + (1,) * (v.ndim - 1)), v, 0)
-        for k, v in out.columns.items()
-    }
-    return Table(cols, out.count)
+        out_cols: dict[str, jax.Array] = {}
+        # representative key per group (all rows in a segment share the key)
+        kmin = jnp.iinfo(keys.dtype).min
+        out_cols[key] = jax.ops.segment_max(
+            jnp.where(valid, keys, kmin), seg, cap + 1
+        )[:cap].astype(keys.dtype)
+        for col, op in aggs.items():
+            vals = t.columns[col]
+            if op not in AGGS:
+                raise ValueError(f"unsupported agg {op!r}; have {sorted(AGGS)}")
+            res = AGGS[op](vals, seg, cap + 1)[:cap]
+            out_cols[f"{col}_{op}"] = res.astype(
+                jnp.int32 if op == "count" else table.columns[col].dtype
+            )
+
+        out = Table(out_cols, n_groups)
+        # zero padding rows for determinism (segment_max yields dtype-min there)
+        mask = out.valid_mask()
+        cols = {
+            k: jnp.where(mask.reshape((-1,) + (1,) * (v.ndim - 1)), v, 0)
+            for k, v in out.columns.items()
+        }
+        return Table(cols, out.count)
 
 
 # ---------------------------------------------------------------------------
@@ -114,22 +111,22 @@ def join_unique(left: Table, right: Table, key: str, how: str = "inner") -> Tabl
     if how != "inner":
         raise NotImplementedError("join_unique supports inner joins")
     r = sort_by_key(right, key)
-    rkeys = jnp.where(r.valid_mask(), r.columns[key], _key_sentinel(r.columns[key].dtype))
-    lkeys = left.columns[key]
-    pos = jnp.searchsorted(rkeys, lkeys)
-    pos_c = jnp.clip(pos, 0, right.capacity - 1)
-    hit = (rkeys[pos_c] == lkeys) & left.valid_mask() & (pos_c < r.count)
+    with stage("probe"):
+        rkeys = jnp.where(r.valid_mask(), r.columns[key], _key_sentinel(r.columns[key].dtype))
+        lkeys = left.columns[key]
+        pos = jnp.searchsorted(rkeys, lkeys)
+        pos_c = jnp.clip(pos, 0, right.capacity - 1)
+        hit = (rkeys[pos_c] == lkeys) & left.valid_mask() & (pos_c < r.count)
 
-    cols: dict[str, jax.Array] = {}
-    for name, col in left.columns.items():
-        cols[name] = col
-    for name, col in r.columns.items():
-        if name == key:
-            continue
-        tag = f"{name}_r" if name in left.columns else name
-        cols[tag] = jnp.take(col, pos_c, axis=0, mode="clip")
-    joined = Table(cols, left.count)
-    return joined.filter(hit)
+    cols: dict[str, jax.Array] = dict(left.columns)
+    with stage("column_gather"):
+        for name, col in r.columns.items():
+            if name == key:
+                continue
+            tag = f"{name}_r" if name in left.columns else name
+            cols[tag] = jnp.take(col, pos_c, axis=0, mode="clip")
+    with stage("output_pack"):
+        return Table(cols, left.count).filter(hit)
 
 
 def join_sorted_expand(

@@ -12,7 +12,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.dataframe.table import Table
+from repro.dataframe.table import Table, stage
 
 _M1 = jnp.uint32(0x85EBCA6B)
 _M2 = jnp.uint32(0xC2B2AE35)
@@ -48,9 +48,10 @@ def hash_columns(table: Table, key_cols: list[str], seed: int = 0) -> jax.Array:
 
 def bucket_ids(table: Table, key_cols: list[str], num_partitions: int, seed: int = 0) -> jax.Array:
     """Destination partition per row; padding rows get the sentinel P."""
-    h = hash_columns(table, key_cols, seed)
-    b = (h % jnp.uint32(num_partitions)).astype(jnp.int32)
-    return jnp.where(table.valid_mask(), b, num_partitions)
+    with stage("partition_hash"):
+        h = hash_columns(table, key_cols, seed)
+        b = (h % jnp.uint32(num_partitions)).astype(jnp.int32)
+        return jnp.where(table.valid_mask(), b, num_partitions)
 
 
 def partition_counts(table: Table, key_cols: list[str], num_partitions: int, seed: int = 0) -> jax.Array:
@@ -77,25 +78,26 @@ def build_partition_payload(
     cap_dst = cap_per_dest or table.capacity
     b = bucket_ids(table, key_cols, p, seed)
 
-    # Stable sort rows by bucket so each partition's rows are contiguous.
-    order = jnp.argsort(b, stable=True)
-    b_sorted = b[order]
-    counts_full = jnp.bincount(b, length=p + 1)[: p]
-    counts = jnp.minimum(counts_full, cap_dst).astype(jnp.int32)
-    starts = jnp.cumsum(counts_full) - counts_full  # [P] group starts in sorted order
+    with stage("partition_pack"):
+        # Stable sort rows by bucket so each partition's rows are contiguous.
+        order = jnp.argsort(b, stable=True)
+        b_sorted = b[order]
+        counts_full = jnp.bincount(b, length=p + 1)[: p]
+        counts = jnp.minimum(counts_full, cap_dst).astype(jnp.int32)
+        starts = jnp.cumsum(counts_full) - counts_full  # [P] group starts in sorted order
 
-    pos_in_group = jnp.arange(table.capacity) - jnp.take(
-        starts, jnp.minimum(b_sorted, p - 1), mode="clip"
-    )
-    dest_row = jnp.where(
-        (b_sorted < p) & (pos_in_group < cap_dst), pos_in_group, cap_dst
-    )  # cap_dst == drop slot
-    dest_slot = jnp.minimum(b_sorted, p - 1)
+        pos_in_group = jnp.arange(table.capacity) - jnp.take(
+            starts, jnp.minimum(b_sorted, p - 1), mode="clip"
+        )
+        dest_row = jnp.where(
+            (b_sorted < p) & (pos_in_group < cap_dst), pos_in_group, cap_dst
+        )  # cap_dst == drop slot
+        dest_slot = jnp.minimum(b_sorted, p - 1)
 
-    payload = {}
-    for name, col in table.columns.items():
-        src = col[order]
-        buf = jnp.zeros((p, cap_dst + 1) + col.shape[1:], col.dtype)
-        buf = buf.at[dest_slot, dest_row].set(src, mode="drop")
-        payload[name] = buf[:, :cap_dst]
-    return payload, counts
+        payload = {}
+        for name, col in table.columns.items():
+            src = col[order]
+            buf = jnp.zeros((p, cap_dst + 1) + col.shape[1:], col.dtype)
+            buf = buf.at[dest_slot, dest_row].set(src, mode="drop")
+            payload[name] = buf[:, :cap_dst]
+        return payload, counts

@@ -20,6 +20,31 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# The device work of every operator, by stage.  Each stage's work runs inside
+# ``jax.named_scope(<stage>)``, so every HLO instruction it lowers to carries
+# the name in its ``op_name`` metadata; ``chipbench/stages.py`` reads each
+# stage's device time from a profiler trace by it.  A scope is compile-time
+# metadata: the compiled instructions are the same with or without it.  No
+# name equals a JAX primitive's, so a path component is never mistaken for one.
+STAGES = (
+    "partition_hash",  # partition.bucket_ids: row hash, bucket, padding sentinel
+    "partition_pack",  # build_partition_payload: argsort by bucket, scatter to [P, cap]
+    "exchange",        # shuffle_spmd's all_to_alls (and the wire codec)
+    "receive_pack",    # from_stacked: pack the P x cap received slots
+    "key_sort",        # ops_local.sort_by_key: argsort and permutation gather
+    "probe",           # join_unique: binary search and hit test
+    "column_gather",   # join_unique: the right table's columns at the probe positions
+    "output_pack",     # join_unique: stable pack of the matched rows (Table.filter)
+    "segment_reduce",  # groupby_agg: segment ids, reductions, padding zeroed
+)
+
+
+def stage(name: str):
+    """The named scope of one of :data:`STAGES`."""
+    if name not in STAGES:
+        raise ValueError(f"unknown stage {name!r}; have {STAGES}")
+    return jax.named_scope(name)
+
 
 @dataclasses.dataclass(frozen=True)
 class Schema:
@@ -169,11 +194,11 @@ def from_stacked(columns: dict[str, jax.Array], counts: jax.Array) -> Table:
     """Build a Table from [P, cap, ...] stacked buckets + per-bucket counts,
     packing all valid rows to the front (the receive side of a shuffle)."""
     p, cap = counts.shape[0], next(iter(columns.values())).shape[1]
-    flat = {k: v.reshape((p * cap,) + v.shape[2:]) for k, v in columns.items()}
-    within = jnp.tile(jnp.arange(cap), p)
-    bucket = jnp.repeat(jnp.arange(p), cap)
-    mask = within < counts[bucket]
-    order = jnp.argsort(jnp.where(mask, 0, 1), stable=True)
-    total = jnp.sum(counts).astype(jnp.int32)
-    out = Table(flat, total)
-    return out.gather(order, total)
+    with stage("receive_pack"):
+        flat = {k: v.reshape((p * cap,) + v.shape[2:]) for k, v in columns.items()}
+        within = jnp.tile(jnp.arange(cap), p)
+        bucket = jnp.repeat(jnp.arange(p), cap)
+        mask = within < counts[bucket]
+        order = jnp.argsort(jnp.where(mask, 0, 1), stable=True)
+        total = jnp.sum(counts).astype(jnp.int32)
+        return Table(flat, total).gather(order, total)

@@ -7,7 +7,9 @@ time.  The topology is described inside a fixture, never while a module is
 imported, and the tests skip where it cannot be described.
 """
 
+import contextlib
 import functools
+import re
 import sys
 from pathlib import Path
 
@@ -21,6 +23,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 import chip_smoke  # noqa: E402
 from benchmarks import common  # noqa: E402
 from benchmarks.scaling_join import WEAK_ROWS  # noqa: E402
+from chipbench import stages  # noqa: E402
 from repro.dataframe import Table, ops_local
 from repro.kernels.flash_attention import kernel as fa_kernel
 from repro.kernels.hash_partition import kernel as hp_kernel
@@ -116,3 +119,48 @@ def test_join_spmd_compiles_on_four_chips_with_all_to_all(topo):
         col, col, counts, col, col, counts).compile()
     assert "all-to-all" in compiled.as_text()
     assert _fits_one_chip(compiled)
+
+
+SMALL_ROWS = 4096
+
+
+def _small_tables(one_chip):
+    col = jax.ShapeDtypeStruct((SMALL_ROWS,), jnp.int32, sharding=one_chip)
+    count = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    return Table({"k": col, "v": col}, count), Table({"k": col, "w": col}, count)
+
+
+def _join_unique_text(one_chip) -> str:
+    return _compile(lambda lt, rt: ops_local.join_unique(lt, rt, "k"),
+                    *_small_tables(one_chip)).as_text()
+
+
+def _computation(hlo: str, name: str) -> str:
+    """The body of computation `name` in a module's text."""
+    start = re.search(rf"^%{re.escape(name)} .*{{$", hlo, re.M)
+    return hlo[start.end():hlo.index("\n}", start.end())]
+
+
+def test_join_unique_probe_loop_fusions_carry_the_probe_stage(one_chip):
+    hlo = _join_unique_text(one_chip)
+    bodies = re.findall(r" while\(.*?\bbody=%([^\s,]+)", hlo)
+    assert bodies
+    of = stages.instruction_stages(hlo)
+    fusions = [of[name] for body in bodies
+               for name in re.findall(r"^\s*(?:ROOT )?%(\S+) = .* fusion\(",
+                                      _computation(hlo, body), re.M)]
+    assert fusions and set(fusions) == {"probe"}
+
+
+def _without_metadata(hlo: str) -> str:
+    hlo = re.sub(r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n.*?\n\n", "", hlo,
+                 flags=re.S | re.M)
+    return re.sub(r",? (metadata=\{[^}]*\}|stack_frame_id=\d+)", "", hlo)
+
+
+def test_stage_scopes_change_no_instruction(one_chip, monkeypatch):
+    scoped = _join_unique_text(one_chip)
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    unscoped = _join_unique_text(one_chip)
+    assert "/probe/" in scoped and "/probe/" not in unscoped
+    assert _without_metadata(scoped) == _without_metadata(unscoped)
