@@ -5,9 +5,9 @@ of the paper's distributed operators.  All are jit-safe over fixed-capacity
 tables; variable-size results use capacity + count + packing.
 
 Algorithms are TPU-minded: sort-based (argsort lowers to a bitonic network on
-TPU), branchless binary-search probes (the Pallas kernel in
-``repro.kernels.join_probe`` implements the probe loop with VMEM tiling), and
-segment reductions (``repro.kernels.segment_reduce``).
+TPU), sort-merge probes that co-sort both key sets and read the matches off
+cumulative scans, with no random gather, and segment reductions
+(``repro.kernels.segment_reduce``).
 """
 
 from __future__ import annotations
@@ -16,6 +16,7 @@ from collections.abc import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.dataframe.table import Table, stage
 
@@ -100,23 +101,73 @@ def groupby_agg(table: Table, key: str, aggs: dict[str, str]) -> Table:
 # ---------------------------------------------------------------------------
 
 
+_SCAN_WIDTH = 128  # one TPU lane row
+
+
+def _inclusive_scan(x: jax.Array, op, identity: int) -> jax.Array:
+    """Inclusive scan of 1-D `x` under `op` (``lax.add``, ``lax.max``).
+
+    Blocked in rows of 128: a windowed reduction along each row, then the
+    scan of the row totals (recursively) added back.  The TPU compiler
+    rewrites a 1-D windowed reduction into this same form but drops the op
+    metadata, and with it the stage name; written out, every op keeps it.
+    """
+    n = x.shape[0]
+    w = min(n, _SCAN_WIDTH)
+    rows = -(-n // w)
+    x2 = jnp.pad(x, (0, rows * w - n), constant_values=identity).reshape(rows, w)
+    x2 = lax.reduce_window(x2, x.dtype.type(identity), op, (1, w), (1, 1), ((0, 0), (w - 1, 0)))
+    if rows > 1:
+        carry = _inclusive_scan(x2[:, -1], op, identity)
+        x2 = op(x2, jnp.pad(carry[:-1], (1, 0), constant_values=identity)[:, None])
+    return x2.reshape(-1)[:n]
+
+
+def _merge_probe(rkeys: jax.Array, r_valid: jax.Array, lkeys: jax.Array):
+    """Lower bound of every left key in the sorted valid right keys, and
+    whether it hits, in left row order, without a gather, a scatter or a loop.
+
+    Both key sets are sorted together on (key, code): a valid right row's code
+    is -1, a left row's its row index, right padding's nl.  So inside one key's
+    run a valid right row comes first, then the left rows, then padding: a
+    valid key equal to the dtype's max still matches, and padding never does.
+    In that order a left slot's lower bound is the count of right rows before
+    it, and it hits iff a right row lies between its run's head and itself.
+    A second sort, on the code, puts the left slots back in row order.  Every
+    index and scan is int32 (x64 would make them int64); capacities fit it.
+    """
+    i32 = jnp.int32
+    nl = lkeys.shape[0]
+    code = jnp.concatenate([jnp.where(r_valid, i32(-1), i32(nl)), jnp.arange(nl, dtype=i32)])
+    keys, code = lax.sort((jnp.concatenate([rkeys, lkeys]), code), num_keys=2, is_stable=False)
+    is_r = (code < 0).astype(i32)
+    n_r = _inclusive_scan(is_r, lax.add, 0)  # right rows up to and including each slot
+    head = jnp.concatenate([jnp.ones((1,), bool), keys[1:] != keys[:-1]])
+    run_start = _inclusive_scan(jnp.where(head, n_r - is_r, 0), lax.max, 0)  # right rows before
+    hit = (code >= 0) & (n_r > run_start)
+    # the lower bound (n_r, less the hit row itself) in the high bits, the hit in bit 0
+    packed = 2 * n_r - hit
+    _, packed = lax.sort((jnp.where(code < 0, i32(nl), code), packed), num_keys=1,
+                         is_stable=False)
+    packed = packed[:nl]
+    return packed >> 1, (packed & 1).astype(bool)
+
+
 def join_unique(left: Table, right: Table, key: str, how: str = "inner") -> Table:
     """Equi-join where `right` has at most one valid row per key.
 
-    Sort-probe: sort right by key, binary-search each left key.  This is the
-    paper's microbenchmark regime (uniform random ~unique keys) and the
-    kernelized path (repro.kernels.join_probe).  Inner join only here;
-    unmatched left rows are dropped (packed out).
+    Sort-merge probe: sort right by key, co-sort its keys with the left keys
+    to find each left key's match (``_merge_probe``).  This is the paper's
+    microbenchmark regime (uniform random ~unique keys).  Inner join only
+    here; unmatched left rows are dropped (packed out).
     """
     if how != "inner":
         raise NotImplementedError("join_unique supports inner joins")
     r = sort_by_key(right, key)
     with stage("probe"):
-        rkeys = jnp.where(r.valid_mask(), r.columns[key], _key_sentinel(r.columns[key].dtype))
-        lkeys = left.columns[key]
-        pos = jnp.searchsorted(rkeys, lkeys)
-        pos_c = jnp.clip(pos, 0, right.capacity - 1)
-        hit = (rkeys[pos_c] == lkeys) & left.valid_mask() & (pos_c < r.count)
+        pos, hit = _merge_probe(r.columns[key], r.valid_mask(), left.columns[key])
+        pos_c = jnp.minimum(pos, right.capacity - 1)
+        hit = hit & left.valid_mask()
 
     cols: dict[str, jax.Array] = dict(left.columns)
     with stage("column_gather"):

@@ -32,7 +32,7 @@ STAGES = (
     "exchange",        # shuffle_spmd's all_to_alls (and the wire codec)
     "receive_pack",    # from_stacked: pack the P x cap received slots
     "key_sort",        # ops_local.sort_by_key: argsort and permutation gather
-    "probe",           # join_unique: binary search and hit test
+    "probe",           # join_unique: co-sort of both key sets, scans for lower bound and hit
     "column_gather",   # join_unique: the right table's columns at the probe positions
     "output_pack",     # join_unique: stable pack of the matched rows (Table.filter)
     "segment_reduce",  # groupby_agg: segment ids, reductions, padding zeroed

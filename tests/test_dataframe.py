@@ -1,6 +1,7 @@
 """DDMF layer: oracles + hypothesis property tests (deliverable (c))."""
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 try:
@@ -154,6 +155,39 @@ class TestLocalOps:
         rmap = dict(zip(rk, rv))
         exp = sorted((k, v, rmap[k]) for k, v in zip(lk, lv) if k in rmap)
         assert got == exp
+
+    I32, I64 = np.iinfo(np.int32), np.iinfo(np.int64)
+    # (left keys, right keys (unique among the valid), left count, right count, dtype);
+    # the rows past each count are padding and hold keys too
+    JOIN_EDGE_CASES = {
+        "left_duplicates": ([5, 3, 5, 5, 9, 3], [3, 5, 7], 6, 3, np.int32),
+        "dtype_max": ([I32.max, 1, I32.max, 2], [2, I32.max, 0], 4, 3, np.int32),
+        "dtype_min": ([I32.min, 1, I32.min], [I32.min, 1], 3, 2, np.int32),
+        "negative": ([-7, -1, 0, -7, 4, -3], [-3, -7, 4, -100], 6, 4, np.int32),
+        "int64_full_range": ([I64.max, I64.min, 0, -(2**62), 2**62 + 1, I64.max, 3],
+                             [2**62 + 1, I64.min, I64.max, -(2**62), 5], 7, 5, np.int64),
+        "left_count_0": ([1, 2, 3], [1, 2, 3], 0, 3, np.int32),
+        "right_count_0": ([1, 2, 3], [1, 2, 3], 3, 0, np.int32),
+        "padding_equals_valid": ([4, 8, 2, 4, 8, 2], [8, 4, 2, 8, 4, 2], 3, 2, np.int32),
+        "padding_at_dtype_max": ([I32.max, 6, I32.max], [6, I32.max, I32.max], 2, 1, np.int32),
+    }
+
+    @pytest.mark.parametrize("case", sorted(JOIN_EDGE_CASES))
+    def test_join_unique_edge_case_matches_nested_loop(self, case):
+        lk, rk, nl, nr, dtype = self.JOIN_EDGE_CASES[case]
+        lv, rv = list(range(100, 100 + len(lk))), list(range(200, 200 + len(rk)))
+        exp = sorted((a, v, w) for a, v in zip(lk[:nl], lv) for b, w in zip(rk[:nr], rv)
+                     if a == b)
+        with jax.enable_x64(dtype == np.int64):
+            def table(keys, vals, count, val_name):
+                return Table({"k": jnp.asarray(np.asarray(keys, dtype)),
+                              val_name: jnp.asarray(np.asarray(vals, dtype))},
+                             jnp.asarray(count, jnp.int32))
+
+            j = ops_local.join_unique(table(lk, lv, nl, "v"), table(rk, rv, nr, "w"), "k")
+            cols = j.to_numpy()
+        assert cols["k"].dtype == dtype
+        assert sorted(zip(*[cols[c].tolist() for c in ("k", "v", "w")])) == exp
 
     @given(st.data())
     @settings(max_examples=25, deadline=None)

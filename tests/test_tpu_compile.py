@@ -135,21 +135,14 @@ def _join_unique_text(one_chip) -> str:
                     *_small_tables(one_chip)).as_text()
 
 
-def _computation(hlo: str, name: str) -> str:
-    """The body of computation `name` in a module's text."""
-    start = re.search(rf"^%{re.escape(name)} .*{{$", hlo, re.M)
-    return hlo[start.end():hlo.index("\n}", start.end())]
-
-
 def test_join_unique_probe_loop_fusions_carry_the_probe_stage(one_chip):
+    """The instructions under the probe stage sort, and hold no loop and no gather."""
     hlo = _join_unique_text(one_chip)
-    bodies = re.findall(r" while\(.*?\bbody=%([^\s,]+)", hlo)
-    assert bodies
     of = stages.instruction_stages(hlo)
-    fusions = [of[name] for body in bodies
-               for name in re.findall(r"^\s*(?:ROOT )?%(\S+) = .* fusion\(",
-                                      _computation(hlo, body), re.M)]
-    assert fusions and set(fusions) == {"probe"}
+    opcodes = {op for name, op in re.findall(r"^\s*(?:ROOT )?%(\S+) = .*? ([\w-]+)\(", hlo, re.M)
+               if of[name] == "probe"}
+    assert "sort" in opcodes
+    assert not opcodes & {"while", "gather"}
 
 
 def _without_metadata(hlo: str) -> str:
