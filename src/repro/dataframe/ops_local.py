@@ -35,6 +35,30 @@ def sort_by_key(table: Table, key: str) -> Table:
         return table.gather(order, table.count)
 
 
+_SCAN_WIDTH = 128  # one TPU lane row
+
+
+def _inclusive_scan(x: jax.Array, op, identity: int) -> jax.Array:
+    """Inclusive scan of 1-D `x` under `op` (``lax.add``, ``lax.max``).
+
+    Blocked in rows of 128: a windowed reduction along each row, then the
+    scan of the row totals (recursively) added back.  The TPU compiler
+    rewrites a 1-D windowed reduction into this same form but drops the op
+    metadata, and with it the stage name; written out, every op keeps it.
+    """
+    n = x.shape[0]
+    if n == 0:
+        return x
+    w = min(n, _SCAN_WIDTH)
+    rows = -(-n // w)
+    x2 = jnp.pad(x, (0, rows * w - n), constant_values=identity).reshape(rows, w)
+    x2 = lax.reduce_window(x2, x.dtype.type(identity), op, (1, w), (1, 1), ((0, 0), (w - 1, 0)))
+    if rows > 1:
+        carry = _inclusive_scan(x2[:, -1], op, identity)
+        x2 = op(x2, jnp.pad(carry[:-1], (1, 0), constant_values=identity)[:, None])
+    return x2.reshape(-1)[:n]
+
+
 # ---------------------------------------------------------------------------
 # GroupBy (paper §IV-C) — sort + segment reduce, with combiner support
 # ---------------------------------------------------------------------------
@@ -68,7 +92,7 @@ def groupby_agg(table: Table, key: str, aggs: dict[str, str]) -> Table:
             [jnp.ones((1,), jnp.int32), (keys_m[1:] != keys_m[:-1]).astype(jnp.int32)]
         )
         new_seg = jnp.where(valid, new_seg, 0)
-        seg = jnp.where(valid, jnp.cumsum(new_seg) - 1, cap)
+        seg = jnp.where(valid, _inclusive_scan(new_seg, lax.add, 0) - 1, cap)
         n_groups = jnp.sum(new_seg).astype(jnp.int32)
 
         out_cols: dict[str, jax.Array] = {}
@@ -99,28 +123,6 @@ def groupby_agg(table: Table, key: str, aggs: dict[str, str]) -> Table:
 # ---------------------------------------------------------------------------
 # Joins
 # ---------------------------------------------------------------------------
-
-
-_SCAN_WIDTH = 128  # one TPU lane row
-
-
-def _inclusive_scan(x: jax.Array, op, identity: int) -> jax.Array:
-    """Inclusive scan of 1-D `x` under `op` (``lax.add``, ``lax.max``).
-
-    Blocked in rows of 128: a windowed reduction along each row, then the
-    scan of the row totals (recursively) added back.  The TPU compiler
-    rewrites a 1-D windowed reduction into this same form but drops the op
-    metadata, and with it the stage name; written out, every op keeps it.
-    """
-    n = x.shape[0]
-    w = min(n, _SCAN_WIDTH)
-    rows = -(-n // w)
-    x2 = jnp.pad(x, (0, rows * w - n), constant_values=identity).reshape(rows, w)
-    x2 = lax.reduce_window(x2, x.dtype.type(identity), op, (1, w), (1, 1), ((0, 0), (w - 1, 0)))
-    if rows > 1:
-        carry = _inclusive_scan(x2[:, -1], op, identity)
-        x2 = op(x2, jnp.pad(carry[:-1], (1, 0), constant_values=identity)[:, None])
-    return x2.reshape(-1)[:n]
 
 
 def _merge_probe(rkeys: jax.Array, r_valid: jax.Array, lkeys: jax.Array):

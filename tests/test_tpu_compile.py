@@ -23,7 +23,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 import chip_smoke  # noqa: E402
 from benchmarks import common  # noqa: E402
 from benchmarks.scaling_join import WEAK_ROWS  # noqa: E402
-from chipbench import stages  # noqa: E402
+from chipbench import groupbyref, run, stages  # noqa: E402
 from repro.dataframe import Table, ops_local
 from repro.kernels.flash_attention import kernel as fa_kernel
 from repro.kernels.hash_partition import kernel as hp_kernel
@@ -119,6 +119,28 @@ def test_join_spmd_compiles_on_four_chips_with_all_to_all(topo):
         col, col, counts, col, col, counts).compile()
     assert "all-to-all" in compiled.as_text()
     assert _fits_one_chip(compiled)
+
+
+def test_groupby_agg_compiles_at_the_groupby_cell_size(one_chip):
+    """``groupby-50m-1chip``'s program: three int64 columns of 55,000,008
+    slots, a summed and b maxed.  It fits one chip, and its instructions carry
+    both stages, the windowed reductions of the segment-id scan included."""
+    _, _, config, _ = run.load_cell("groupby-50m-1chip")
+    with jax.enable_x64(True):
+        col = jax.ShapeDtypeStruct((groupbyref.capacity(config),), jnp.int64,
+                                   sharding=one_chip)
+        table = Table({c: col for c in config["columns"]},
+                      jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+        compiled = _compile(lambda t: ops_local.groupby_agg(t, config["key"], config["aggs"]),
+                            table)
+    assert groupbyref.capacity(config) == 55_000_008
+    assert _fits_one_chip(compiled)
+    hlo = compiled.as_text()
+    of = stages.instruction_stages(hlo)
+    assert {"key_sort", "segment_reduce"} <= set(of.values())
+    windows = [name for name, op in re.findall(r"^\s*(?:ROOT )?%(\S+) = .*? ([\w-]+)\(", hlo,
+                                               re.M) if op == "reduce-window"]
+    assert windows and {of[name] for name in windows} == {"segment_reduce"}
 
 
 SMALL_ROWS = 4096
