@@ -6,13 +6,11 @@ tables; variable-size results use capacity + count + packing.
 
 Algorithms are TPU-minded: sort-based (argsort lowers to a bitonic network on
 TPU), sort-merge probes that co-sort both key sets and read the matches off
-cumulative scans, with no random gather, and segment reductions
-(``repro.kernels.segment_reduce``).
+cumulative scans, with no random gather, and segment reductions as segmented
+scans over the key-sorted rows, compacted by one sort, with no scatter.
 """
 
 from __future__ import annotations
-
-from collections.abc import Callable
 
 import jax
 import jax.numpy as jnp
@@ -59,25 +57,65 @@ def _inclusive_scan(x: jax.Array, op, identity: int) -> jax.Array:
     return x2.reshape(-1)[:n]
 
 
+def _segmented_scan(x: jax.Array, seg: jax.Array, op) -> jax.Array:
+    """Inclusive scan of 1-D `x` under the associative `op`, restarted wherever
+    the segment id changes; `seg` holds each row's id, and each segment's rows
+    are contiguous.
+
+    Blocked in rows of 128, as ``_inclusive_scan``: inside a row, log2(128)
+    doubling steps, each folding in the partial of the row 2^i lanes back if it
+    lies in the same segment; then the scan of the row ends (recursively) is
+    folded into the lanes that continue the previous row's last segment.  Each
+    step combines two adjacent runs of one segment, the earlier first, so the
+    result is exact for any associative `op`, with no identity, gather or
+    scatter.
+    """
+    n = x.shape[0]
+    if n == 0:
+        return x
+    w = min(n, _SCAN_WIDTH)
+    rows = -(-n // w)
+    x2 = jnp.pad(x, (0, rows * w - n)).reshape(rows, w)
+    s2 = jnp.pad(seg, (0, rows * w - n)).reshape(rows, w)
+    lane = lax.broadcasted_iota(jnp.int32, (1, w), 1)
+    d = 1
+    while d < w:
+        back = ((0, 0), (d, 0))
+        same = (lane >= d) & (jnp.pad(s2[:, :-d], back) == s2)
+        x2 = jnp.where(same, op(jnp.pad(x2[:, :-d], back), x2), x2)
+        d *= 2
+    if rows > 1:
+        ends = _segmented_scan(x2[:, -1], s2[:, -1], op)
+        row = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        same = (row > 0) & (jnp.pad(s2[:-1, -1], (1, 0))[:, None] == s2)
+        x2 = jnp.where(same, op(jnp.pad(ends[:-1], (1, 0))[:, None], x2), x2)
+    return x2.reshape(-1)[:n]
+
+
 # ---------------------------------------------------------------------------
-# GroupBy (paper §IV-C) — sort + segment reduce, with combiner support
+# GroupBy (paper §IV-C) — sort + segmented scans, with combiner support
 # ---------------------------------------------------------------------------
 
-AGGS: dict[str, Callable] = {
-    "sum": lambda vals, seg, n: jax.ops.segment_sum(vals, seg, num_segments=n),
-    "max": lambda vals, seg, n: jax.ops.segment_max(vals, seg, num_segments=n),
-    "min": lambda vals, seg, n: jax.ops.segment_min(vals, seg, num_segments=n),
-    "count": lambda vals, seg, n: jax.ops.segment_sum(jnp.ones_like(vals), seg, num_segments=n),
-}
+_AGG_OPS = {"sum": lax.add, "max": lax.max, "min": lax.min, "count": lax.add}
 
 
 def groupby_agg(table: Table, key: str, aggs: dict[str, str]) -> Table:
-    """Group by integer `key`; aggregate value columns with AGGS ops.
+    """Group by integer `key`; aggregate value columns by op name.
 
-    Output: one row per distinct key (packed), capacity preserved.
-    `aggs` maps value-column name -> op name.  The mean op is expressed by the
-    caller as sum+count (associativity needed for the distributed combiner).
+    The rows are sorted by key; each aggregate is a segmented scan over them,
+    which holds the segment's aggregate at its last row (``count`` scans ones),
+    and one sort on the segment id of those last rows packs them, in key order,
+    to the front.  The reduction is exact at every integer width and does no
+    scatter.
+
+    Output: one row per distinct key (packed), capacity preserved, padding
+    zeroed.  `aggs` maps value-column name -> op name.  The mean op is
+    expressed by the caller as sum+count (associativity needed for the
+    distributed combiner).
     """
+    for op in aggs.values():
+        if op not in _AGG_OPS:
+            raise ValueError(f"unsupported agg {op!r}; have {sorted(_AGG_OPS)}")
     t = sort_by_key(table, key)
     with stage("segment_reduce"):
         keys = t.columns[key]
@@ -87,37 +125,26 @@ def groupby_agg(table: Table, key: str, aggs: dict[str, str]) -> Table:
         cap = table.capacity
 
         # Segment ids: 0-based rank of each distinct key in sorted order; invalid
-        # rows are parked in an overflow segment `cap` that is sliced away.
+        # rows form one trailing segment `cap`, sorted away below.
         new_seg = jnp.concatenate(
             [jnp.ones((1,), jnp.int32), (keys_m[1:] != keys_m[:-1]).astype(jnp.int32)]
         )
         new_seg = jnp.where(valid, new_seg, 0)
         seg = jnp.where(valid, _inclusive_scan(new_seg, lax.add, 0) - 1, cap)
         n_groups = jnp.sum(new_seg).astype(jnp.int32)
+        # a segment's last row: valid, and the next row starts a segment or is padding
+        last = valid & jnp.concatenate([(new_seg[1:] == 1) | ~valid[1:], jnp.ones((1,), bool)])
 
-        out_cols: dict[str, jax.Array] = {}
-        # representative key per group (all rows in a segment share the key)
-        kmin = jnp.iinfo(keys.dtype).min
-        out_cols[key] = jax.ops.segment_max(
-            jnp.where(valid, keys, kmin), seg, cap + 1
-        )[:cap].astype(keys.dtype)
+        out_cols: dict[str, jax.Array] = {key: keys}  # every row of a segment holds its key
         for col, op in aggs.items():
-            vals = t.columns[col]
-            if op not in AGGS:
-                raise ValueError(f"unsupported agg {op!r}; have {sorted(AGGS)}")
-            res = AGGS[op](vals, seg, cap + 1)[:cap]
-            out_cols[f"{col}_{op}"] = res.astype(
-                jnp.int32 if op == "count" else table.columns[col].dtype
-            )
+            vals = jnp.ones((cap,), jnp.int32) if op == "count" else t.columns[col]
+            out_cols[f"{col}_{op}"] = _segmented_scan(vals, seg, _AGG_OPS[op])
 
-        out = Table(out_cols, n_groups)
-        # zero padding rows for determinism (segment_max yields dtype-min there)
-        mask = out.valid_mask()
-        cols = {
-            k: jnp.where(mask.reshape((-1,) + (1,) * (v.ndim - 1)), v, 0)
-            for k, v in out.columns.items()
-        }
-        return Table(cols, out.count)
+        _, *packed = lax.sort((jnp.where(last, seg, cap), *out_cols.values()), num_keys=1,
+                              is_stable=False)
+        mask = jnp.arange(cap) < n_groups
+        return Table({name: jnp.where(mask, col, 0) for name, col in zip(out_cols, packed)},
+                     n_groups)
 
 
 # ---------------------------------------------------------------------------

@@ -35,7 +35,7 @@ STAGES = (
     "probe",           # join_unique: co-sort of both key sets, scans for lower bound and hit
     "column_gather",   # join_unique: the right table's columns at the probe positions
     "output_pack",     # join_unique: stable pack of the matched rows (Table.filter)
-    "segment_reduce",  # groupby_agg: segment ids, reductions, padding zeroed
+    "segment_reduce",  # groupby_agg: segment ids, segmented scans, compacting sort, padding zeroed
 )
 
 

@@ -130,14 +130,68 @@ class TestLocalOps:
             oracle[k] = oracle.get(k, 0) + int(v)
         assert got == oracle
 
-    def test_groupby_max_min_count(self):
-        t = make_table([1, 1, 2, 2, 2], [5, -3, 7, 7, 1], cap=8)
-        g = ops_local.groupby_agg(t, "k", {"v": "max"})
-        got = dict(zip(g.to_numpy()["k"].tolist(), g.to_numpy()["v_max"].tolist()))
-        assert got == {1: 5, 2: 7}
-        g = ops_local.groupby_agg(t, "k", {"v": "count"})
-        got = dict(zip(g.to_numpy()["k"].tolist(), g.to_numpy()["v_count"].tolist()))
-        assert got == {1: 2, 2: 3}
+    I32, I64 = np.iinfo(np.int32), np.iinfo(np.int64)
+    GROUPBY_AGGS = {"a": "sum", "b": "max", "c": "min", "d": "count"}
+    groupby = staticmethod(jax.jit(
+        lambda t: ops_local.groupby_agg(t, "k", TestLocalOps.GROUPBY_AGGS)))
+
+    def check_groupby(self, keys, vals, count):
+        """groupby_agg over int64 columns k, a = vals >> 24 (its sums fit), b = c =
+        vals, against a dict oracle; the rows past `count` are padding and hold
+        keys and values too."""
+        keys, vals = np.asarray(keys, np.int64), np.asarray(vals, np.int64)
+        cols = {"k": keys, "a": vals >> 24, "b": vals, "c": vals, "d": vals}
+        oracle: dict[int, tuple[int, int, int, int]] = {}
+        for k, a, v in zip(keys[:count].tolist(), cols["a"][:count].tolist(), vals.tolist()):
+            s, hi, lo, n = oracle.get(k, (0, v, v, 0))
+            oracle[k] = (s + a, max(hi, v), min(lo, v), n + 1)
+        with jax.enable_x64(True):
+            t = Table({c: jnp.asarray(v) for c, v in cols.items()}, jnp.asarray(count, jnp.int32))
+            g = self.groupby(t)
+            out = {c: np.asarray(v) for c, v in g.columns.items()}
+        n = int(g.count)
+        assert n == len(oracle) and g.capacity == len(keys)
+        names = ["a_sum", "b_max", "c_min", "d_count"]
+        assert [out[c].dtype for c in ["k", *names]] == [np.int64] * 4 + [np.int32]
+        assert out["k"][:n].tolist() == sorted(oracle)
+        got = {k: tuple(out[c][i].item() for c in names)
+               for i, k in enumerate(out["k"][:n].tolist())}
+        assert got == oracle
+        assert all(not out[c][n:].any() for c in out)  # the output's padding is zero
+
+    @given(st.lists(st.integers(-20, 20), min_size=0, max_size=90), st.integers(0, 2**32))
+    @settings(max_examples=30, deadline=None)
+    def test_groupby_int64_aggs_match_dict_oracle(self, keys, seed):
+        """sum, max, min and count together, values over the whole int64 range,
+        extremes included, and keys and values in the padding drawn the same way.
+        The drawn keys repeat up to 40 times, so segments span the scans' rows
+        of 128."""
+        rng = np.random.default_rng(seed)
+        keys = np.repeat(keys, rng.integers(1, 41)).tolist()
+        pad = -(-(len(keys) + 1) // 1024) * 1024 - len(keys)  # few capacities, few compiles
+        keys = keys + rng.integers(-20, 21, pad).tolist()
+        vals = rng.integers(self.I64.min, self.I64.max, len(keys), endpoint=True)
+        extreme = rng.random(len(keys)) < 0.2
+        vals[extreme] = rng.choice([self.I64.min, self.I64.max], int(extreme.sum()))
+        self.check_groupby(keys, vals, len(keys) - pad)
+
+    # (keys, values, count): the rows past `count` are padding
+    GROUPBY_CASES = {
+        "max_min_count": ([1, 1, 2, 2, 2, 0, 0, 0], [5, -3, 7, 7, 1, 0, 0, 0], 5),
+        "empty": ([3, 1, 3, 2], [I64.max, 5, I64.min, -1], 0),
+        "one_group": ([7] * 9 + [8], [I64.max, 1, I64.min, -5, 0, I64.max, 2, 3, -2**40, 9], 9),
+        "all_distinct": ([9, -4, I64.max, 0, I64.min, 5, 2, 6], [3, 1, 4, 1, 5, 9, 2, 6], 8),
+        "count_equals_capacity": ([2, 1, 2, 1, 1, 3], [I64.min, I64.max, 0, -7, 7, 1], 6),
+        "garbage_in_padding": ([5, 1, 5, 1, 5, 1, I64.max, 1],
+                               [1, 2, 3, 4, I64.max, I64.min, I64.max, I64.min], 4),
+        # more than 128 x 128 rows: the scans recurse twice, and segments span both
+        "long_segments": ((np.arange(40_000) % 3).tolist(),
+                          np.random.default_rng(5).integers(-2**62, 2**62, 40_000), 39_990),
+    }
+
+    @pytest.mark.parametrize("case", sorted(GROUPBY_CASES))
+    def test_groupby_max_min_count(self, case):
+        self.check_groupby(*self.GROUPBY_CASES[case])
 
     @given(st.data())
     @settings(max_examples=30, deadline=None)
@@ -156,7 +210,6 @@ class TestLocalOps:
         exp = sorted((k, v, rmap[k]) for k, v in zip(lk, lv) if k in rmap)
         assert got == exp
 
-    I32, I64 = np.iinfo(np.int32), np.iinfo(np.int64)
     # (left keys, right keys (unique among the valid), left count, right count, dtype);
     # the rows past each count are padding and hold keys too
     JOIN_EDGE_CASES = {

@@ -123,8 +123,9 @@ def test_join_spmd_compiles_on_four_chips_with_all_to_all(topo):
 
 def test_groupby_agg_compiles_at_the_groupby_cell_size(one_chip):
     """``groupby-50m-1chip``'s program: three int64 columns of 55,000,008
-    slots, a summed and b maxed.  It fits one chip, and its instructions carry
-    both stages, the windowed reductions of the segment-id scan included."""
+    slots, a summed and b maxed.  It fits one chip, holds no scatter, and every
+    sort, fusion and windowed reduction outside the key sort is the segment
+    reduction's: its scans and its compacting sort."""
     _, _, config, _ = run.load_cell("groupby-50m-1chip")
     with jax.enable_x64(True):
         col = jax.ShapeDtypeStruct((groupbyref.capacity(config),), jnp.int64,
@@ -137,10 +138,11 @@ def test_groupby_agg_compiles_at_the_groupby_cell_size(one_chip):
     assert _fits_one_chip(compiled)
     hlo = compiled.as_text()
     of = stages.instruction_stages(hlo)
-    assert {"key_sort", "segment_reduce"} <= set(of.values())
-    windows = [name for name, op in re.findall(r"^\s*(?:ROOT )?%(\S+) = .*? ([\w-]+)\(", hlo,
-                                               re.M) if op == "reduce-window"]
-    assert windows and {of[name] for name in windows} == {"segment_reduce"}
+    ops = re.findall(r"^\s*(?:ROOT )?%(\S+) = .*? ([\w-]+)\(", hlo, re.M)
+    assert "scatter" not in {op for _, op in ops}
+    work = [(of[name], op) for name, op in ops if op in {"sort", "fusion", "reduce-window"}]
+    assert {stage for stage, _ in work} == {"key_sort", "segment_reduce"}
+    assert sorted(stage for stage, op in work if op == "sort") == ["key_sort", "segment_reduce"]
 
 
 SMALL_ROWS = 4096
